@@ -68,11 +68,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from repro.core import costs, shingles, sparsify, tables
 from repro.core.merge import apply_merges, select_matching
 from repro.core.types import PairTable, SummaryConfig, SummaryState, init_state
-from repro.dist import make_rules, shard_map
+from repro.dist import make_rules
 from repro.kernels import ops as kops
 from repro.utils import boundaries_from_keys, segment_ids_from_boundaries
 
@@ -276,7 +277,10 @@ class DistributedBackend:
         return 2.0 * self.num_edges * float(np.log2(max(self.num_nodes, 2)))
 
     def init(self) -> SummaryState:
-        return init_state(self.num_nodes, self.cfg.seed)
+        # placed as every chunk's output is, so the first chunk and the
+        # later ones run one compiled program
+        return jax.device_put(init_state(self.num_nodes, self.cfg.seed),
+                              self.state_sharding())
 
     def run_chunk(self, state, thetas, t0, k_bits, limit):
         src_p, dst_p = self._shards()

@@ -71,6 +71,28 @@ def apply_merges(
     )
 
 
+def scoring_operands(
+    src: jax.Array,
+    dst: jax.Array,
+    state: SummaryState,
+    cfg: SummaryConfig,
+    k_groups: jax.Array,
+) -> tuple[tables.GroupTables, dict[str, jax.Array]]:
+    """Pair table, Eq. (2)/(4) metrics and the candidate groups' merge-gain
+    operands of one round (Alg. 1 line 5) — everything the kernel reads."""
+    v = state.node2super.shape[0]
+    e = src.shape[0]
+    pt = costs.build_pair_table(src, dst, state)
+    metrics = costs.summary_metrics(
+        pt, state, v, e, cbar_mode=cfg.cbar_mode, re_guard=cfg.re_guard
+    )
+    groups = shingles.build_groups(src, dst, state, k_groups, cfg.group_size)
+    gt = tables.build_group_tables(
+        pt, state, groups, cfg.max_neighbors, cfg.union_size, metrics["cbar"], v
+    )
+    return gt, metrics
+
+
 def merge_iteration(
     src: jax.Array,
     dst: jax.Array,
@@ -80,23 +102,12 @@ def merge_iteration(
 ) -> tuple[SummaryState, dict[str, jax.Array]]:
     """One full candidate-generation + merging round (Alg. 1 lines 5–7)."""
     v = state.node2super.shape[0]
-    e = src.shape[0]
     rng, k_groups = jax.random.split(state.rng)
     state = SummaryState(
         node2super=state.node2super, size=state.size, rng=rng, t=state.t
     )
 
-    pt = costs.build_pair_table(src, dst, state)
-    metrics = costs.summary_metrics(
-        pt, state, v, e, cbar_mode=cfg.cbar_mode, re_guard=cfg.re_guard
-    )
-    cbar = metrics["cbar"]
-    log2v = jnp.log2(jnp.float32(v))
-
-    groups = shingles.build_groups(src, dst, state, k_groups, cfg.group_size)
-    gt = tables.build_group_tables(
-        pt, state, groups, cfg.max_neighbors, cfg.union_size, cbar, v
-    )
+    gt, metrics = scoring_operands(src, dst, state, cfg, k_groups)
     rel, red = kops.merge_gain(
         gt.m,
         gt.n,
@@ -105,8 +116,8 @@ def merge_iteration(
         gt.n_u,
         gt.cidx,
         gt.w,
-        cbar,
-        log2v,
+        metrics["cbar"],
+        jnp.log2(jnp.float32(v)),
         backend=kops.resolve_kernel_backend(cfg.kernel_backend),
     )
     a, b, sel = select_matching(rel, gt.members, theta)

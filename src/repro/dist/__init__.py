@@ -10,12 +10,8 @@ Three concerns, one vocabulary:
     error-feedback buffers for the cross-pod gradient boundary;
   * :mod:`repro.dist.microbatch` — gradient accumulation that matches the
     full-batch gradient.
-
-:mod:`repro.dist.compat` isolates the jax-version differences (shard_map
-location, mesh axis types) so the rest of the tree imports one stable API.
 """
 
-from repro.dist.compat import make_mesh, shard_map
 from repro.dist.compress import (
     CompressConfig,
     compressed_allreduce,
@@ -26,7 +22,7 @@ from repro.dist.compress import (
     payload_bytes,
 )
 from repro.dist.microbatch import microbatch_grads
-from repro.dist.sharding import MeshRules, make_rules, owner_hash_np
+from repro.dist.sharding import MeshRules, make_mesh, make_rules, owner_hash_np
 
 __all__ = [
     "CompressConfig",
@@ -41,5 +37,4 @@ __all__ = [
     "microbatch_grads",
     "owner_hash_np",
     "payload_bytes",
-    "shard_map",
 ]

@@ -38,7 +38,7 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 # Tensor-parallel parameter/activation dimensions: sharded over "model" in
@@ -56,6 +56,12 @@ _LOGICAL = _TP_AXES + (
 OWNER_HASH_MULT = 2654435761
 
 MODES = ("train", "serve", "summarize", "eval")
+
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` over every device, all axes auto-partitioned."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def owner_hash_np(ids, salt: int, n_devices: int) -> "np.ndarray":

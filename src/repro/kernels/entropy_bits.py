@@ -33,7 +33,7 @@ def _pair_cost_kernel(scal_ref, cnt_ref, pi_ref, out_ref):
 
 def pair_cost_pallas(
     cnt: jax.Array, pi: jax.Array, cbar: jax.Array, log2v: jax.Array,
-    *, interpret: bool = True,
+    *, interpret: bool,
 ) -> jax.Array:
     """1-D tiled fused pair cost; pads to a BLOCK multiple internally."""
     (e,) = cnt.shape

@@ -71,7 +71,7 @@ def resolve_kernel_backend(name: str | None = None) -> str:
     return name
 
 
-def backend_from_flags(use_pallas: bool, interpret: bool = True) -> str:
+def backend_from_flags(use_pallas: bool, interpret: bool = False) -> str:
     """Compat shim: the retired ``use_pallas``/``interpret`` bool pair →
     registry name. New code should pass backend names directly."""
     if not use_pallas:

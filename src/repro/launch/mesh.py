@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 import os
 
-from repro.dist.compat import make_mesh
+from repro.dist.sharding import make_mesh
 
 #: environment fallbacks for the bootstrap flags — one launch command can be
 #: broadcast to every host with only these three variables differing.

@@ -48,6 +48,7 @@ from repro.core.queries_jax import (
     pack_set_counts,
 )
 from repro.graphs import DATASETS, load_graph
+from repro.launch.device import device_info, enable_compile_cache
 from repro.runtime import make_mesh_from_plan, plan_mesh
 
 
@@ -185,6 +186,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--pagerank-iters", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     kind_names = [k.strip() for k in args.queries.split(",") if k.strip()]
     unknown = [k for k in kind_names if k not in KIND_NAMES]
@@ -264,6 +266,7 @@ def main(argv=None) -> dict:
         "engine_build_wall_s": build_wall_s,
         "answers_digest": answers_digest(server.done),
         "source": g.source,
+        "device": device_info(),
     }
     if owner_counts is not None:
         result["owner_counts"] = owner_counts

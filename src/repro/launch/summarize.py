@@ -52,6 +52,7 @@ from repro.graphs.feed import (
     shard_edges_from_cache,
     shard_edges_from_cache_multihost,
 )
+from repro.launch.device import device_info, enable_compile_cache
 from repro.launch.mesh import bootstrap_distributed
 from repro.runtime import (
     RESUMABLE_EXIT,
@@ -129,6 +130,7 @@ def run_distributed(src, dst, v, cfg: SummaryConfig, mesh, pipeline=None,
     sp_stats = {k: float(x) for k, x in run.finalize["stats"].items()}
     sp_stats["sparsify_wall_s"] = run.sparsify_wall_s
     out.update(sp_stats)
+    out["iterations"] = run.iterations_run
     out["chunk_wall_s"] = run.chunk_wall_s
     out["straggler_events"] = [dataclasses.asdict(ev)
                                for ev in run.straggler_events]
@@ -202,6 +204,7 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
+    enable_compile_cache()
 
     # multi-host bootstrap FIRST — jax.distributed.initialize must run
     # before anything queries device state (single-process: no-op)
@@ -342,6 +345,7 @@ def main(argv=None) -> dict:
             wall_s=time.time() - t0), indent=1))
         raise SystemExit(RESUMABLE_EXIT)
     result.update(ingest)
+    result["device"] = device_info()
     if ckp is not None:
         result["checkpoint_dir"] = args.checkpoint_dir
     result["peak_rss_mb"] = peak_rss_mb()

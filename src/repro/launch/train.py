@@ -51,9 +51,9 @@ from repro.runtime import (
 
 
 def build_train_step(model, rules, run: RunConfig, accum: int, mesh=None):
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import shard_map
     from repro.dist.compress import compressed_allreduce
 
     def loss_fn(p, b):

@@ -20,8 +20,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.dist import shard_map
 from repro.models.common import Px, dense_init
 from repro.utils import boundaries_from_keys, rank_in_segment
 

@@ -28,7 +28,7 @@ import signal
 
 import numpy as np
 
-from repro.dist.compat import make_mesh
+from repro.dist.sharding import make_mesh
 
 #: exit status of a run that checkpointed and stopped on SIGTERM/SIGINT —
 #: nonzero (the work is unfinished) but *resumable* (EX_TEMPFAIL).

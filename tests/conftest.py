@@ -1,14 +1,8 @@
-"""Test-session setup: fall back to the deterministic hypothesis stub when
-the real library is unavailable (no-network test images)."""
+"""Test-session setup: put ``tests/`` on ``sys.path`` so test modules can
+share fixtures by import (``test_partition_tables`` imports
+``test_queries_jax``)."""
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-try:
-    import hypothesis  # noqa: F401  (prefer the real library when present)
-except ImportError:
-    import _hypothesis_fallback
-
-    _hypothesis_fallback.install()

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from repro.core import queries as Q
-from repro.core.queries_jax import build_partition_tables, host_padded_rows
+from repro.core.queries_jax import build_partition_tables
 from repro.dist.sharding import owner_hash_np
 from test_queries_jax import _random_summary
 
@@ -42,7 +42,6 @@ def test_halo_coverage(n_dev, dense_row_nnz):
     rng = np.random.default_rng(100 * n_dev + (dense_row_nnz or 7))
     for _ in range(10):
         bs, owner, t = _tables_for(rng, n_dev, dense_row_nnz)
-        pad_cols, _, _ = host_padded_rows(bs)
         s_own, h = t.own_gids.shape[1], t.halo_gids.shape[1]
         ht = t.row_halo_gids.shape[1]
         dmax = t.dense_slots.shape[1]
@@ -51,18 +50,30 @@ def test_halo_coverage(n_dev, dense_row_nnz):
         for q in range(n_dev):
             own = t.own_gids[q][t.own_gids[q] >= 0]
             assert np.array_equal(own, np.flatnonzero(owner == q))
-            n_own = own.size
-            refs_mask = pad_cols[own] >= 0
+            # the owned entries are the owned rows' CSR entries, row-major
+            n_ent = int(t.own_indptr[q, -1])
+            ent = t.own_ent[q, :n_ent]
+            want = [np.arange(bs.indptr[g], bs.indptr[g + 1]) for g in own]
+            assert np.array_equal(ent, np.concatenate(want + [[]]))
+            assert np.all(t.own_ent[q, n_ent:] == -1)
+            assert np.array_equal(np.diff(t.own_indptr[q, :own.size + 1]),
+                                  np.diff(bs.indptr)[own])
             # every real reference resolves below the sentinel; every
             # padding entry resolves TO the sentinel
-            loc_share = t.loc_share[q, :n_own]
-            loc_row = t.loc_row[q, :n_own]
-            assert np.all(loc_share[refs_mask] < share_sent)
-            assert np.all(loc_share[~refs_mask] == share_sent)
-            assert np.all(loc_row[refs_mask] < row_sent)
-            assert np.all(loc_row[~refs_mask] == row_sent)
+            assert np.all(t.loc_share[q, :n_ent] < share_sent)
+            assert np.all(t.loc_share[q, n_ent:] == share_sent)
+            assert np.all(t.loc_row[q, :n_ent] < row_sent)
+            assert np.all(t.loc_row[q, n_ent:] == row_sent)
+            # ... and to the entry's own column
+            ext_share = np.concatenate([t.own_gids[q], t.halo_gids[q]])
+            assert np.array_equal(ext_share[t.loc_share[q, :n_ent]],
+                                  bs.cols[ent])
+            ext_row = np.concatenate([t.own_gids[q], t.row_halo_gids[q],
+                                      t.dense_slots.ravel()])
+            assert np.array_equal(ext_row[t.loc_row[q, :n_ent]],
+                                  bs.cols[ent])
             # the share-side halo is exactly the remote referenced blocks
-            refs = np.unique(pad_cols[own][refs_mask])
+            refs = np.unique(bs.cols[ent])
             remote = refs[owner[refs] != q]
             assert np.array_equal(t.halo_gids[q][t.halo_gids[q] >= 0],
                                   remote)
@@ -87,7 +98,8 @@ def test_tables_deterministic_across_rebuilds():
     owner = owner_hash_np(bs.ids, 17, 8)
     a = build_partition_tables(bs, owner, 8, dense_row_nnz=2)
     b = build_partition_tables(bs, owner, 8, dense_row_nnz=2)
-    for name in ("owner", "block_pos", "own_gids", "halo_gids",
+    for name in ("owner", "block_pos", "own_gids", "own_ent",
+                 "own_indptr", "halo_gids",
                  "halo_src_dev", "halo_src_pos", "row_halo_gids",
                  "dense_gids", "dense_slots", "loc_share", "loc_row"):
         assert np.array_equal(getattr(a, name), getattr(b, name)), name

@@ -31,7 +31,10 @@ from repro.core.queries_jax import (
     KIND_PAGERANK,
     KIND_TRIANGLE,
     QueryEngine,
+    enable_x64,
     pack_set_counts,
+    planned_row_sums,
+    row_sum_plans,
 )
 from repro.core.types import SummaryResult
 from repro.graphs import generate
@@ -313,3 +316,40 @@ def test_engine_accepts_plain_python_and_numpy_targets(dtype):
     v = res.node2super.shape[0]
     one = eng.expected_degree(np.asarray([v - 1], dtype))
     assert one.shape == (1,) and one.dtype == np.float64
+
+
+@pytest.mark.parametrize("lengths", [[0], [1, 0, 3], [4, 5, 16, 17, 0, 2],
+                                     list(range(40))])
+def test_row_sum_plan_sums_each_row_identically(lengths):
+    """Planned per-row sums agree with numpy, and split over two devices'
+    stacked plans every row gets the same bits as in the one-device plan
+    — the property the partitioned tier's bit-identity rests on."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(len(lengths))
+    lengths = np.asarray(lengths)
+    starts = np.cumsum(lengths) - lengths
+    vals = rng.random(int(lengths.sum()))
+    want = np.array([vals[a:a + n].sum() for a, n in zip(starts, lengths)])
+    def plan_of(stacked, q):
+        return jax.tree_util.tree_map(lambda x: jnp.asarray(x[q]), stacked)
+
+    with enable_x64():
+        one = np.asarray(planned_row_sums(plan_of(row_sum_plans([lengths]), 0),
+                                          jnp.asarray(vals)))
+        np.testing.assert_allclose(one, want, rtol=1e-12)
+        rows = [np.arange(0, lengths.size, 2), np.arange(1, lengths.size, 2)]
+        width = max(r.size for r in rows)
+        dev_len = np.zeros((2, width), np.int64)
+        dev_vals = np.zeros((2, max(1, int(lengths.sum()))))
+        for q, r in enumerate(rows):
+            dev_len[q, :r.size] = lengths[r]
+            mine = np.concatenate(
+                [vals[starts[i]:starts[i] + lengths[i]] for i in r] + [[]])
+            dev_vals[q, :mine.size] = mine
+        stacked = row_sum_plans(dev_len)
+        for q, r in enumerate(rows):
+            got = np.asarray(planned_row_sums(plan_of(stacked, q),
+                                              jnp.asarray(dev_vals[q])))
+            np.testing.assert_array_equal(got[:r.size], one[r])

@@ -1,0 +1,107 @@
+"""The main path's Pallas kernels compile for a TPU v5e at amazon0601 size,
+and the partitioned query tier compiles over a v5e 2x2 mesh.
+
+Nothing runs: the TPU compiler, installed with jax, compiles for a v5e
+that is described, not attached, so this guards the Mosaic layout rules
+(block shapes, 2-D values, SMEM scalars) that interpret mode never checks,
+and the collectives the TPU supports (it all-reduces float64 only by sum).
+A whole ``merge.merge_iteration`` at these shapes is left out: its sorts
+take minutes to compile for the TPU.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from repro.kernels.entropy_bits import pair_cost_pallas
+from repro.kernels.merge_gain import merge_gain_pallas
+
+V, E = 403_394, 2_419_961  # amazon0601 stand-in after canonicalization
+C, U = 32, 128  # SummaryConfig.group_size, .union_size
+G = -(-V // C)  # 12,607 candidate groups
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2 (libtpu loads only in this fixture).
+
+    The persistent compilation cache is off meanwhile: an executable for a
+    described chip is written to it but cannot be read back without one."""
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        cache_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield topo
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_merge_gain_compiles_for_v5e(one_chip):
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = _compile(
+        functools.partial(merge_gain_pallas, interpret=False), one_chip,
+        ((G, C, U), f32), ((G, C), f32), ((G, C), f32), ((G, C), f32),
+        ((G, U), f32), ((G, C), i32), ((G, C, C), f32), ((), f32), ((), f32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pair_cost_compiles_for_v5e(one_chip):
+    f32 = jnp.float32
+    compiled = _compile(
+        functools.partial(pair_cost_pallas, interpret=False), one_chip,
+        ((E,), f32), ((E,), f32), ((), f32), ((), f32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_partitioned_queries_compile_for_v5e_2x2(topo, monkeypatch):
+    from repro.core import queries_jax as QJ
+    from test_queries_jax import _random_summary
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    rep = NamedSharding(mesh, PartitionSpec())
+    # a described chip holds no arrays: the engine's leaves become shapes
+    monkeypatch.setattr(jax, "device_put", lambda x, sh: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sh))
+    eng = QJ.PartitionedQueryEngine(
+        _random_summary(np.random.default_rng(0)), mesh)
+    s, b = eng.tables.s, 8
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=rep)
+
+    batch = (shape((b,), jnp.int32),) * 3 + (shape((s,), jnp.float64),
+                                             shape((), jnp.float64))
+    sets = (shape((b, s), jnp.float64),) * 3
+    with QJ.enable_x64(), mesh:
+        for program, args in ((eng._pagerank, ()), (eng._triangle, ()),
+                              (eng._answer, batch),
+                              (eng._answer_full, batch + sets)):
+            compiled = program.lower(eng.part, eng.rep, *args).compile()
+            assert compiled.as_text()

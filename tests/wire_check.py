@@ -37,9 +37,10 @@ dist = bootstrap_distributed()  # env-driven; no-op single-process
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import make_mesh, shard_map
+from repro.dist import make_mesh
 from repro.dist.compress import (
     CompressConfig,
     compressed_allreduce,
