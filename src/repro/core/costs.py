@@ -88,6 +88,7 @@ def keep_superedge(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("pair_table")
 def build_pair_table(src: jax.Array, dst: jax.Array, state: SummaryState) -> PairTable:
     """Aggregate the edge list into per-supernode-pair subedge counts.
 
@@ -146,6 +147,7 @@ def cbar_value(
     return 2.0 * jnp.log2(s) + jnp.log2(w)
 
 
+@jax.named_scope("summary_metrics")
 def summary_metrics(
     pt: PairTable,
     state: SummaryState,

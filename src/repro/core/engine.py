@@ -301,6 +301,7 @@ class SummaryEngine:
         # converged: θ=0 accepts any cost-reducing merge; none left
         return stats["nmerges"] == 0 and theta == 0.0
 
+    @functools.partial(jax.profiler.annotate_function, name="ssumm.engine")
     def run(self, collect_history: bool = True, *,
             checkpointer: EngineCheckpointer | None = None,
             monitor: StragglerMonitor | None = None,
@@ -321,6 +322,8 @@ class SummaryEngine:
         brackets every device dispatch with ``begin_step``/``end_step``;
         flagged events land in ``EngineRun.straggler_events`` and per-chunk
         wall times in ``EngineRun.chunk_wall_s``.
+
+        The whole run is the host span ``ssumm.engine`` on a profiler trace.
         """
         cfg, backend = self.cfg, self.backend
         size_g = backend.input_size_bits()
@@ -357,8 +360,6 @@ class SummaryEngine:
                 state = backend.init()
         else:
             state = backend.init()
-
-        t_wall = time.perf_counter()
 
         def run_rounds(state, t0: int, limit: int, thetas: list[float]):
             """One device dispatch of ≤ ``limit`` rounds; host-side unpack."""
@@ -409,13 +410,10 @@ class SummaryEngine:
             thetas = [theta_schedule_host(tt, cfg.T)
                       for tt in range(t, t + limit)]
             state, rows = run_rounds(state, t, limit, thetas)
-            wall = time.perf_counter() - t_wall
             for i, row in enumerate(rows):
                 last = row
                 if collect_history:
-                    history.append(
-                        dict(row, t=t + i, theta=thetas[i], wall_s=wall)
-                    )
+                    history.append(dict(row, t=t + i, theta=thetas[i]))
             t += len(rows)
             last_theta = thetas[len(rows) - 1]
             stopped = self._should_stop(last, last_theta, k_bits)
@@ -437,10 +435,7 @@ class SummaryEngine:
                 state, rows = run_rounds(state, t, 1, [0.0])
                 last = rows[0]
                 if collect_history:
-                    history.append(dict(
-                        rows[0], t=t, theta=0.0,
-                        wall_s=time.perf_counter() - t_wall,
-                    ))
+                    history.append(dict(rows[0], t=t, theta=0.0))
                 t += 1
                 extra_done += 1
                 if last["nmerges"] == 0:
@@ -456,9 +451,11 @@ class SummaryEngine:
             phase = "final"
             sync_point(state, force=True)
 
+        # waits for the device, so that the finalize's time is its own (and
+        # the ``ssumm.engine`` span's), not the first host copy's after it
         t_sp = time.perf_counter()
-        finalize = backend.sparsify_finalize(state, k_bits,
-                                             iterations_run + 1)
+        finalize = jax.block_until_ready(
+            backend.sparsify_finalize(state, k_bits, iterations_run + 1))
         sparsify_wall_s = time.perf_counter() - t_sp
         snapshot_wall = 0.0
         if ck is not None:

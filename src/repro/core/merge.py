@@ -31,6 +31,7 @@ def theta_schedule(t: jax.Array, big_t: int) -> jax.Array:
     return jnp.where(t < big_t, 1.0 / (1.0 + t.astype(jnp.float32)), 0.0)
 
 
+@jax.named_scope("matching")
 def select_matching(
     rel: jax.Array,  # f32[G, C, C]
     members: jax.Array,  # i32[G, C]
@@ -50,6 +51,7 @@ def select_matching(
     return a.reshape(-1), b.reshape(-1), accept.reshape(-1)
 
 
+@jax.named_scope("matching")
 def apply_merges(
     state: SummaryState, a: jax.Array, b: jax.Array, sel: jax.Array
 ) -> tuple[SummaryState, jax.Array]:
@@ -124,9 +126,10 @@ def merge_iteration(
     new_state, nmerges = apply_merges(state, a, b, sel)
     # summed Eq. 20 absolute reduction (bits) of the accepted pairs: gather
     # each row's best-partner red — the same argmax select_matching used
-    best_j = jnp.argmax(rel, axis=-1)
-    red_best = jnp.take_along_axis(red, best_j[..., None], axis=-1)[..., 0]
-    total_reduction = jnp.sum(jnp.where(sel, red_best.reshape(-1), 0.0))
+    with jax.named_scope("matching"):
+        best_j = jnp.argmax(rel, axis=-1)
+        red_best = jnp.take_along_axis(red, best_j[..., None], axis=-1)[..., 0]
+        total_reduction = jnp.sum(jnp.where(sel, red_best.reshape(-1), 0.0))
     new_state = SummaryState(
         node2super=new_state.node2super,
         size=new_state.size,

@@ -83,6 +83,7 @@ def chunk_groups_lean(shingle: jax.Array, group_size: int) -> jax.Array:
     return order.reshape(-1, group_size)
 
 
+@jax.named_scope("shingles")
 def build_groups(
     src: jax.Array,
     dst: jax.Array,
@@ -96,6 +97,7 @@ def build_groups(
     return chunk_groups(sh, state.size, k_tie, group_size)
 
 
+@jax.named_scope("shingles")
 def build_groups_from_pairs(
     plo: jax.Array,
     phi: jax.Array,

@@ -152,6 +152,7 @@ def select_delta_xi(delta: jax.Array, keep: jax.Array, xi: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sparsify")
 def further_sparsify(
     pt: PairTable,
     state: SummaryState,

@@ -23,6 +23,9 @@ device→host sync every round.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import numpy as np
 
 from repro.core.engine import LocalBackend, SummaryEngine
@@ -46,12 +49,21 @@ def summarize(
     ``monitor`` (a :class:`repro.runtime.straggler.StragglerMonitor`), and
     ``resume`` pass straight through to :meth:`SummaryEngine.run` — the
     crash-safe/preemption-safe path of DESIGN.md §13.
+
+    On a profiler trace one call is three host spans in turn:
+    ``ssumm.make_graph``, ``ssumm.engine`` and ``ssumm.result`` (the host
+    copies of the summary and the result's assembly).
     """
     backend = LocalBackend(src, dst, num_nodes, cfg)
     run = SummaryEngine(backend).run(collect_history=collect_history,
                                      checkpointer=checkpointer,
                                      monitor=monitor, resume=resume)
+    return _result(run)
 
+
+@functools.partial(jax.profiler.annotate_function, name="ssumm.result")
+def _result(run) -> SummaryResult:
+    """The summary's host copies, assembled into the result."""
     pt = run.finalize["pair_table"]
     after = run.finalize["after"]
     keep_np = np.asarray(run.finalize["keep"])

@@ -144,6 +144,7 @@ def supernode_total_costs_compact(
     return out[:-1]
 
 
+@jax.named_scope("group_tables")
 def build_group_tables(
     pt: PairTable,
     state: SummaryState,
@@ -217,11 +218,22 @@ def assemble_group_tables(
     gi = jnp.broadcast_to(
         jnp.arange(g_cnt, dtype=jnp.int32)[:, None], (g_cnt, c * d)
     )
-    col_safe = jnp.where(entry_ok, col, u)  # OOB → dropped
-    uid = jnp.full((g_cnt, u + 1), v, jnp.int32)
-    uid = uid.at[gi, col_safe].min(jnp.where(entry_ok, ids_s, v))[:, :u]
-    m = jnp.zeros((g_cnt, c, u + 1), jnp.float32)
-    m = m.at[gi, row_s, col_safe].add(jnp.where(entry_ok, cnt_s, 0.0))[:, :, :u]
+    col_safe = jnp.where(entry_ok, col, u)  # spare column U, sliced off
+    # One flat index per entry. The TPU compiler rewrites a scatter over
+    # several indices into this form itself, and the rewritten scatter
+    # loses its name stack (the ``group_tables`` scope that names it in a
+    # profile); written flat here, it keeps it.
+    if g_cnt * c * (u + 1) >= 2**31:
+        raise ValueError(f"{g_cnt}x{c}x{u + 1} group tables pass int32 "
+                         "indexing")
+    at_uid = (gi * (u + 1) + col_safe).reshape(-1)
+    uid = jnp.full((g_cnt * (u + 1),), v, jnp.int32).at[at_uid].min(
+        jnp.where(entry_ok, ids_s, v).reshape(-1))
+    uid = uid.reshape(g_cnt, u + 1)[:, :u]
+    at_m = ((gi * c + row_s) * (u + 1) + col_safe).reshape(-1)
+    m = jnp.zeros((g_cnt * c * (u + 1),), jnp.float32).at[at_m].add(
+        jnp.where(entry_ok, cnt_s, 0.0).reshape(-1))
+    m = m.reshape(g_cnt, c, u + 1)[:, :, :u]
 
     n_u = jnp.where(uid < v, sizes[jnp.minimum(uid, v - 1)], 0).astype(
         jnp.float32

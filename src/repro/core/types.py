@@ -8,6 +8,7 @@ dead ids are marked by ``size == 0``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -151,8 +152,10 @@ class SummaryResult:
     checkpoint_snapshot_wall_s: float = 0.0
 
 
+@functools.partial(jax.profiler.annotate_function, name="ssumm.make_graph")
 def make_graph(src, dst, num_nodes: int) -> tuple[Graph, int]:
-    """Canonicalize an edge list: undirected, dedup, no self-loops, src<dst."""
+    """Canonicalize an edge list: undirected, dedup, no self-loops, src<dst,
+    and copy it to the device (host span ``ssumm.make_graph``)."""
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
     lo = np.minimum(src, dst)

@@ -156,5 +156,6 @@ def merge_gain_pallas(
         out_specs=[out_spec, out_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
+        name="ssumm_merge_gain",
     )(scal, m, col(n), col(s), col(t), n_u.reshape(g, 1, u), col(cidx), w)
     return rel, red

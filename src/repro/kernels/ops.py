@@ -80,6 +80,7 @@ def backend_from_flags(use_pallas: bool, interpret: bool = False) -> str:
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
+@jax.named_scope("merge_gain")
 def merge_gain(m, n, s, t, n_u, cidx, w, cbar, log2v, *, backend=None):
     """(rel, red) gain matrices [G, C, C] — Eq. (20)/(17) per candidate pair."""
     impl, _ = _REGISTRY[resolve_kernel_backend(backend)]

@@ -21,14 +21,20 @@ CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
-    With ``$JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and
-    nothing is set here. Otherwise the cache goes to :data:`CACHE_DIR`.
+    With ``$JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and no
+    directory is set here. Otherwise the cache goes to :data:`CACHE_DIR`.
+
+    The cache key covers each program's name stack (its ``named_scope``s),
+    which JAX leaves out by default: an executable cached from code that
+    named its layers otherwise is then never run in its place, so a
+    profiler trace names the code as it stands.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(CACHE_ENV)
     if env:
         return env
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     return str(CACHE_DIR)
 

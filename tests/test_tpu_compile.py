@@ -6,10 +6,12 @@ that is described, not attached, so this guards the Mosaic layout rules
 (block shapes, 2-D values, SMEM scalars) that interpret mode never checks,
 and the collectives the TPU supports (it all-reduces float64 only by sum).
 A whole ``merge.merge_iteration`` at these shapes is left out: its sorts
-take minutes to compile for the TPU.
+take minutes to compile for the TPU. The merge round is compiled at a tiny
+size instead, for the names its layers keep through the TPU compiler.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +72,8 @@ def test_merge_gain_compiles_for_v5e(one_chip):
         ((G, C, U), f32), ((G, C), f32), ((G, C), f32), ((G, C), f32),
         ((G, U), f32), ((G, C), i32), ((G, C, C), f32), ((), f32), ((), f32))
     assert "tpu_custom_call" in compiled.as_text()
+    # found by its stable name inside the ``merge_gain`` scope
+    assert "ssumm_merge_gain" in compiled.as_text()
 
 
 def test_pair_cost_compiles_for_v5e(one_chip):
@@ -78,6 +82,40 @@ def test_pair_cost_compiles_for_v5e(one_chip):
         functools.partial(pair_cost_pallas, interpret=False), one_chip,
         ((E,), f32), ((E,), f32), ((), f32), ((), f32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_round_keeps_its_layer_names_on_v5e(one_chip):
+    """Every sort and scatter of the merge round carries one of the round's
+    named scopes after the TPU compiler's rewrites, which drop the name
+    stack of a scatter over several indices."""
+    from repro.core import SummaryConfig
+    from repro.core.engine import _local_chunk
+    from repro.core.types import SummaryState
+
+    v, e = 256, 1500
+    cfg = SummaryConfig(T=4, k_frac=0.3)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    state = SummaryState(node2super=shape((v,), jnp.int32),
+                         size=shape((v,), jnp.int32),
+                         rng=shape(key.shape, key.dtype),
+                         t=shape((), jnp.int32))
+    text = _local_chunk.lower(
+        shape((e,), jnp.int32), shape((e,), jnp.int32), state,
+        shape((cfg.driver_chunk,), jnp.float32), shape((), jnp.float32),
+        shape((), jnp.int32), cfg).compile().as_text()
+    layers = {"pair_table", "summary_metrics", "shingles", "group_tables",
+              "merge_gain", "matching"}
+    named = []
+    for line in text.splitlines():
+        if re.search(r"\s(sort|scatter)\(", line.split("metadata=")[0]):
+            scope = re.search(r'op_name="([^"]*)"', line)
+            named.append(bool(scope) and bool(
+                layers & set(scope.group(1).split("/"))))
+    assert named and all(named)
 
 
 def test_partitioned_queries_compile_for_v5e_2x2(topo, monkeypatch):
