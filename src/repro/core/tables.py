@@ -7,6 +7,15 @@ members are assigned up to ``U`` columns, so the neighbor multiset of member
 is simply ``M[i] + M[j]`` — turning the paper's sorted-list unions into MXU
 friendly dense arithmetic.
 
+The union space is built without a scatter or a gather over the group's
+``C·D`` entries: one sort of the members' ids, a second sort that moves
+the first occurrence of each id to the front (the ``U`` smallest distinct
+ids, ascending), and compare-reduces
+``M[i, j] = Σ_d cnt[i, d]·[id[i, d] = uid[j]]`` (and the within-group
+counts against the member ids) that XLA fuses, so no ``[C, D, U]`` compare
+is stored. Each sum adds one count to zeros, as an id occurs once in a
+member's row (a repeated id adds its counts up), so the tables are exact.
+
 Exactness contract: scoring sees the top-``D`` heaviest neighbors of each
 member (≤ ``U`` union columns); everything that falls off the tables is
 carried by the *exact* per-supernode totals ``t_A = Cost*_A(S)`` as a
@@ -202,42 +211,24 @@ def assemble_group_tables(
     tab_id = jnp.where(alive[..., None], nbr_id[rows], v)  # [G, C, D]
     tab_cnt = jnp.where(alive[..., None], nbr_cnt[rows], 0.0)
 
-    # ---- union space: batched sort along the last axis ------------------
-    flat_id = tab_id.reshape(g_cnt, c * d)
-    flat_cnt = tab_cnt.reshape(g_cnt, c * d)
-    row = jnp.broadcast_to(
-        jnp.arange(c, dtype=jnp.int32)[None, :, None], (g_cnt, c, d)
-    ).reshape(g_cnt, c * d)
-    ids_s, row_s, cnt_s = jax.lax.sort((flat_id, row, flat_cnt), num_keys=1)
+    # ---- union space: the U smallest distinct ids, ascending -------------
+    ids_s = jnp.sort(tab_id.reshape(g_cnt, c * d), axis=1)
     first = jnp.concatenate(
         [jnp.ones((g_cnt, 1), bool), ids_s[:, 1:] != ids_s[:, :-1]], axis=1
     )
-    col = jnp.cumsum(first.astype(jnp.int32), axis=1) - 1  # [G, C*D]
-    entry_ok = (ids_s < v) & (col < u)
-
-    gi = jnp.broadcast_to(
-        jnp.arange(g_cnt, dtype=jnp.int32)[:, None], (g_cnt, c * d)
-    )
-    col_safe = jnp.where(entry_ok, col, u)  # spare column U, sliced off
-    # One flat index per entry. The TPU compiler rewrites a scatter over
-    # several indices into this form itself, and the rewritten scatter
-    # loses its name stack (the ``group_tables`` scope that names it in a
-    # profile); written flat here, it keeps it.
-    if g_cnt * c * (u + 1) >= 2**31:
-        raise ValueError(f"{g_cnt}x{c}x{u + 1} group tables pass int32 "
-                         "indexing")
-    at_uid = (gi * (u + 1) + col_safe).reshape(-1)
-    uid = jnp.full((g_cnt * (u + 1),), v, jnp.int32).at[at_uid].min(
-        jnp.where(entry_ok, ids_s, v).reshape(-1))
-    uid = uid.reshape(g_cnt, u + 1)[:, :u]
-    at_m = ((gi * c + row_s) * (u + 1) + col_safe).reshape(-1)
-    m = jnp.zeros((g_cnt * c * (u + 1),), jnp.float32).at[at_m].add(
-        jnp.where(entry_ok, cnt_s, 0.0).reshape(-1))
-    m = m.reshape(g_cnt, c, u + 1)[:, :, :u]
-
+    # a second sort moves the repeats, now V, behind the distinct ids (V
+    # pads the columns where a group has fewer than U table slots)
+    uid = jnp.sort(jnp.where(first, ids_s, v), axis=1)[:, :u]
+    uid = jnp.pad(uid, ((0, 0), (0, u - uid.shape[1])), constant_values=v)
     n_u = jnp.where(uid < v, sizes[jnp.minimum(uid, v - 1)], 0).astype(
         jnp.float32
     )
+
+    # member→union counts as a compare-reduce over each member's own row:
+    # a column matches at most one slot of a row (a repeated id adds up)
+    hit = (tab_id[..., None] == uid[:, None, None, :]) & (
+        uid < v)[:, None, None, :]  # [G, C, D, U], fused into the sum
+    m = jnp.sum(jnp.where(hit, tab_cnt[..., None], 0.0), axis=2)
 
     # member's own column in union space (U = absent)
     eq = (uid[:, None, :] == midx[:, :, None]) & alive[:, :, None]  # [G,C,U]
@@ -245,10 +236,12 @@ def assemble_group_tables(
     cidx = jnp.where(found, jnp.argmax(eq, axis=-1).astype(jnp.int32), u)
 
     # within-group pair counts from either row's table (max recovers entries
-    # truncated out of one of the two rows)
-    cj = jnp.minimum(cidx, u - 1)[:, None, :]  # [G,1,C]
-    w1 = jnp.take_along_axis(m, jnp.broadcast_to(cj, (g_cnt, c, c)), axis=2)
-    w1 = jnp.where((cidx < u)[:, None, :], w1, 0.0)
+    # truncated out of one of the two rows); a member outside the union
+    # (id -1 here) scores no within-group edge, as it has no column in m
+    own = jnp.where(found, midx, -1)
+    w1 = jnp.sum(jnp.where(
+        tab_id[:, :, None, :] == own[:, None, :, None],
+        tab_cnt[:, :, None, :], 0.0), axis=3)  # [G, C, C]
     w = jnp.maximum(w1, jnp.swapaxes(w1, 1, 2))
 
     return GroupTables(m=m, n=n, s=s, t=t, n_u=n_u, cidx=cidx, w=w, members=members)

@@ -7,7 +7,8 @@ that is described, not attached, so this guards the Mosaic layout rules
 and the collectives the TPU supports (it all-reduces float64 only by sum).
 A whole ``merge.merge_iteration`` at these shapes is left out: its sorts
 take minutes to compile for the TPU. The merge round is compiled at a tiny
-size instead, for the names its layers keep through the TPU compiler.
+size instead, for the names its layers keep through the TPU compiler, and
+the group tables' assembly alone at the benchmark cells' shapes.
 """
 
 import functools
@@ -82,6 +83,24 @@ def test_pair_cost_compiles_for_v5e(one_chip):
         functools.partial(pair_cost_pallas, interpret=False), one_chip,
         ((E,), f32), ((E,), f32), ((), f32), ((), f32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_group_tables_compile_without_scatter_for_v5e(one_chip):
+    """The union space at the benchmark cells' shapes is built by sorts and
+    compare-reduces: no scatter, and the [G, C, D, U] compare is fused into
+    its sum (held in memory it alone would be 4.3 GB of temporaries)."""
+    from repro.core.tables import assemble_group_tables
+
+    v, d, g, c, u = 131_072, 64, 4_096, 32, 128
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = _compile(
+        functools.partial(assemble_group_tables, row_of_member=None,
+                          union_size=u, num_nodes=v), one_chip,
+        ((v, d), i32), ((v, d), f32), ((v,), f32), ((v,), f32), ((v,), i32),
+        ((g, c), i32))
+    assert not re.search(r"\sscatter\(", compiled.as_text())
+    # the temporaries of the scatter form it replaced, for the same v5e
+    assert compiled.memory_analysis().temp_size_in_bytes <= 147_219_456
 
 
 def test_round_keeps_its_layer_names_on_v5e(one_chip):
