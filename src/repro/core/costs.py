@@ -7,8 +7,8 @@ closed-form per supernode pair ``{A,B}`` from only two aggregates:
     cnt = |E_AB|   (number of subedges between A and B)
     pi  = |Π_AB|   (number of possible subedges: n_A·n_B, or n_A(n_A-1)/2)
 
-so the whole evaluation reduces to one sort + segment-reduce over the
-immutable edge list — no |V|² adjacency matrices anywhere.
+so the whole evaluation reduces to one sort of the immutable edge list,
+whose runs are the pairs — no |V|² adjacency matrices anywhere.
 """
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import PairTable, SummaryState
-from repro.utils import boundaries_from_keys, segment_ids_from_boundaries
+from repro.utils import boundaries_from_keys, segment_length_at_start
 
 # ---------------------------------------------------------------------------
 # Entropy encodings (Eq. 9, Eq. 10)
@@ -84,7 +84,7 @@ def keep_superedge(
 
 
 # ---------------------------------------------------------------------------
-# Pair table: partition → {(A,B) : |E_AB| > 0} via sort + segment reduce
+# Pair table: partition → {(A,B) : |E_AB| > 0} via one sort, in sorted order
 # ---------------------------------------------------------------------------
 
 
@@ -93,22 +93,21 @@ def build_pair_table(src: jax.Array, dst: jax.Array, state: SummaryState) -> Pai
     """Aggregate the edge list into per-supernode-pair subedge counts.
 
     Sorting uses two int32 keys (``lo``, ``hi``) via ``lax.sort`` so no int64
-    composite key is needed (TPU-friendly).
+    composite key is needed (TPU-friendly). The table stays in that sorted
+    order: each pair is one run, its first entry is the pair's valid row and
+    holds the run's length; the run's other entries are invalid with
+    ``cnt`` 0. No scatter compacts the runs (the TPU applies a scatter one
+    index at a time).
     """
-    e = src.shape[0]
     su = state.node2super[src]
     sv = state.node2super[dst]
     lo = jnp.minimum(su, sv)
     hi = jnp.maximum(su, sv)
     lo_s, hi_s = jax.lax.sort((lo, hi), num_keys=2)
     is_new = boundaries_from_keys(lo_s, hi_s)
-    pid = segment_ids_from_boundaries(is_new)
-    npairs = pid[-1] + 1
-    cnt = jax.ops.segment_sum(jnp.ones((e,), jnp.float32), pid, num_segments=e)
-    plo = jnp.zeros((e,), jnp.int32).at[pid].max(lo_s)
-    phi = jnp.zeros((e,), jnp.int32).at[pid].max(hi_s)
-    valid = jnp.arange(e, dtype=jnp.int32) < npairs
-    return PairTable(lo=plo, hi=phi, cnt=jnp.where(valid, cnt, 0.0), valid=valid)
+    # exact in float32: E < 2^24
+    cnt = segment_length_at_start(is_new).astype(jnp.float32)
+    return PairTable(lo=lo_s, hi=hi_s, cnt=cnt, valid=is_new)
 
 
 def pair_pi(pt: PairTable, size: jax.Array) -> jax.Array:

@@ -73,6 +73,12 @@ class PairTable:
     Fixed capacity ``E`` rows (a partition can induce at most ``E`` distinct
     supernode pairs with nonzero subedge count). ``valid`` masks live rows.
     Self-pairs are rows with ``lo == hi``.
+
+    As ``costs.build_pair_table`` returns it, the table is the edge list in
+    sorted ``(lo, hi)`` order: the valid rows are not a prefix but the first
+    entry of each pair's run, in ascending ``(lo, hi)`` order, each with the
+    run's length as ``cnt``. The other rows repeat a real pair's ids and
+    carry ``cnt`` 0, so every reader masks with ``valid`` or ``cnt``.
     """
 
     lo: jax.Array  # int32[E]

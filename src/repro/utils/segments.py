@@ -35,6 +35,20 @@ def rank_in_segment(is_new: jax.Array) -> jax.Array:
     return idx - segment_start(is_new)
 
 
+def segment_length_at_start(is_new: jax.Array) -> jax.Array:
+    """Length of each segment at its first element, 0 elsewhere (sorted layout).
+
+    A segment ends where the next element opens one (or at the array's end);
+    a reverse cumulative minimum carries that end back to every element, so
+    no scatter is needed. Returns int32.
+    """
+    n = is_new.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    closes = jnp.concatenate([is_new[1:], jnp.ones((1,), bool)])
+    end = jax.lax.cummin(jnp.where(closes, idx, n), reverse=True)
+    return jnp.where(is_new, end - idx + 1, 0)
+
+
 def boundaries_from_keys(*keys: jax.Array) -> jax.Array:
     """``is_new`` flags for a lexicographically sorted multi-key array."""
     ks = keys[0]

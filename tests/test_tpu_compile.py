@@ -7,8 +7,9 @@ that is described, not attached, so this guards the Mosaic layout rules
 and the collectives the TPU supports (it all-reduces float64 only by sum).
 A whole ``merge.merge_iteration`` at these shapes is left out: its sorts
 take minutes to compile for the TPU. The merge round is compiled at a tiny
-size instead, for the names its layers keep through the TPU compiler, and
-the group tables' assembly alone at the benchmark cells' shapes.
+size instead, for the names its layers keep through the TPU compiler, the
+group tables' assembly alone at the benchmark cells' shapes, and the pair
+table alone at a small one.
 """
 
 import functools
@@ -101,6 +102,27 @@ def test_group_tables_compile_without_scatter_for_v5e(one_chip):
     assert not re.search(r"\sscatter\(", compiled.as_text())
     # the temporaries of the scatter form it replaced, for the same v5e
     assert compiled.memory_analysis().temp_size_in_bytes <= 147_219_456
+
+
+def test_pair_table_compiles_without_scatter_for_v5e(one_chip):
+    """The pair table is one two-key sort kept in sorted order, with its run
+    lengths from a reverse cumulative min: no scatter compacts the runs.
+    Small shapes: at the benchmark cells' E the compile takes a minute."""
+    from repro.core.costs import build_pair_table
+    from repro.core.types import SummaryState
+
+    v, e = 1_024, 8_192
+    i32 = jnp.int32
+
+    def pair_table(src, dst, node2super):
+        state = SummaryState(node2super=node2super, size=jnp.ones((v,), i32),
+                             rng=jnp.zeros((2,), jnp.uint32), t=jnp.int32(1))
+        return build_pair_table(src, dst, state)
+
+    text = _compile(pair_table, one_chip, ((e,), i32), ((e,), i32),
+                    ((v,), i32)).as_text()
+    assert re.search(r"\ssort\(", text)
+    assert not re.search(r"\sscatter\(", text)
 
 
 def test_round_keeps_its_layer_names_on_v5e(one_chip):
