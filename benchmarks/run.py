@@ -1,5 +1,4 @@
-"""Benchmark orchestrator: one suite per paper table/figure + the roofline
-table from the dry-run artifacts.
+"""Benchmark orchestrator: one suite per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run            # standard suite
     PYTHONPATH=src python -m benchmarks.run --quick    # CI-sized
@@ -20,12 +19,12 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", nargs="+", default=None,
-                    help="subset: table2 fig4 fig5 fig6 fig8 kernels roofline")
+                    help="subset: table2 fig4 fig5 fig6 fig8 fidelity kernels")
     args = ap.parse_args()
 
     quick = args.quick
     suites = args.only or ["table2", "fig4", "fig5", "fig6", "fig8",
-                           "fidelity", "kernels", "roofline"]
+                           "fidelity", "kernels"]
     t_start = time.time()
 
     if "table2" in suites:
@@ -97,11 +96,6 @@ def main() -> None:
         print("# --- kernels: merge-gain throughput ------------------------")
         kernelbench.run(sizes=((64, 32, 128),) if quick
                         else ((256, 32, 128), (64, 64, 256)))
-
-    if "roofline" in suites:
-        from benchmarks import roofline
-        print("# --- roofline: dry-run artifact table ----------------------")
-        roofline.run()
 
     print(f"# total bench wall: {time.time()-t_start:.1f}s")
 

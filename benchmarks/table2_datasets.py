@@ -6,7 +6,7 @@ stand-in. The ``source`` column labels which one backed the row
 (``real|cache|synthetic|spec``). Whenever a graph is actually loaded —
 from a real file *or* a stand-in — ``size_g_bits`` is Eq. (3) on the
 *realized* |V|, |E|; only never-materialized web-scale rows fall back to
-the spec values (``source="spec"``, dry-run only).
+the spec values (``source="spec"``).
 """
 
 from __future__ import annotations
@@ -48,13 +48,13 @@ def run(scale=0.05, materialize_max_e=5_000_000) -> list[dict]:
                     costs.input_size_bits(g.num_nodes, g.num_edges),
             })
         else:
-            # dry-run only: never materialized, so Eq. (3) on the spec
+            # never materialized, so Eq. (3) on the spec
             # values is all there is — labeled, not silently mixed in
             row.update({"source": "spec", "scale": 0, "V": spec.v,
                         "E": spec.e_target,
                         "size_g_bits":
                             costs.input_size_bits(spec.v, spec.e_target),
-                        "note": "dry-run only"})
+                        "note": "spec only"})
         rows.append(row)
         emit(row)
     save_artifact("table2_datasets", rows)

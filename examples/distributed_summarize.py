@@ -2,8 +2,7 @@
 
     python examples/distributed_summarize.py      # no PYTHONPATH needed
 
-Spawns 8 placeholder devices (the same mechanism the multi-pod dry-run
-uses at 512), shards the edge list over a (2, 4) mesh, and runs the
+Spawns 8 placeholder host devices, shards the edge list over a (2, 4) mesh, and runs the
 paper's iteration loop through the SummaryEngine (DESIGN.md §12) over the
 edge-sharded DistributedBackend: all_to_all pair-exchange + owner-local
 merge rounds, with up to ``cfg.driver_chunk`` rounds per device dispatch

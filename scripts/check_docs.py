@@ -17,10 +17,9 @@ REQUIRED = ("DESIGN.md", "README.md", "EXPERIMENTS.md")
 # them — the documented API surface of record. New subsystems register
 # their section here (e.g. §10: streaming ingestion / CSR cache).
 REQUIRED_SECTIONS = {
-    "DESIGN.md": {"1", "2", "3", "4", "5", "6", "7", "8", "9", "10", "11",
-                  "12", "13", "14", "15", "16"},
-    "EXPERIMENTS.md": {"Dry-run", "Roofline", "Perf", "Memory", "Resume",
-                       "Queries"},
+    "DESIGN.md": {"1", "2", "3", "4", "5", "7", "9", "10", "11", "12",
+                  "13", "14", "15", "16"},
+    "EXPERIMENTS.md": {"Perf", "Memory", "Resume", "Queries"},
 }
 
 # README headings other docs/source point operators at by name — same
@@ -82,7 +81,7 @@ def main() -> int:
                     for m in re.finditer(r"(?:DESIGN|EXPERIMENTS)\.md", text)]
         # attribute each §-token to the nearest preceding doc mention
         # within a window — survives line wraps ("...EXPERIMENTS.md\n
-        # §Dry-run") and ranges ("DESIGN.md §3/§4"); a token with no
+        # §Memory") and ranges ("DESIGN.md §3/§4"); a token with no
         # nearby mention (e.g. a bare "§Perf iteration" note) is skipped
         for m in re.finditer(r"§([\w-]+)", text):
             near = [d for p, d in mentions if 0 <= m.start() - p <= 120]

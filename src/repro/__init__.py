@@ -1,3 +1,3 @@
-"""repro — SSumM (KDD 2020) sparse graph summarization + distributed LM substrate."""
+"""repro — SSumM (KDD 2020) sparse graph summarization and summary queries in jax."""
 
 __version__ = "1.0.0"

@@ -4,8 +4,8 @@ Scale story (the paper's headline): one 64 GB host caps the reference
 implementation at ~0.8 B edges; here edges are sharded over every mesh axis
 while the partition vector (``node2super``/``size``, 4 B/node) is replicated
 — web-uk-05 (39.5 M nodes, 0.78 B edges) takes ~12 MB of edges + ~316 MB of
-replicated state per chip on a 256-chip pod (dry-run proof in EXPERIMENTS.md
-§Dry-run).
+replicated state per chip on a 256-chip pod (by arithmetic; no run at that
+size exists).
 
 Scheme (DESIGN.md §7):
   * **ownership**: two interchangeable groupings of one backend
@@ -299,7 +299,7 @@ class DistributedBackend:
         from jax.sharding import NamedSharding
 
         return NamedSharding(self.mesh,
-                             make_rules(self.mesh, "summarize").replicated)
+                             make_rules(self.mesh).replicated)
 
     def sparsify_finalize(self, state, k_bits, salt) -> dict:
         src_p, dst_p = self._shards()
@@ -333,7 +333,7 @@ def make_distributed_backend(mesh, cfg: SummaryConfig, num_nodes: int,
     if grouping not in ("hash", "compact"):
         raise ValueError(f"unknown grouping {grouping!r}; "
                          f"valid: ['compact', 'hash']")
-    rules = make_rules(mesh, "summarize")
+    rules = make_rules(mesh)
     axis_names = rules.axis_names
     n_dev = rules.n_devices
     v = num_nodes
@@ -707,7 +707,7 @@ def make_grouping_fn(mesh, cfg: SummaryConfig, num_nodes: int,
     Returns a jitted fn: (src_l, dst_l, state) → groups_all [G_pad, C]
     (replicated), with G padded to the mesh device count.
     """
-    rules = make_rules(mesh, "summarize")
+    rules = make_rules(mesh)
     axis_names = rules.axis_names
     n_dev = rules.n_devices
     v = num_nodes

@@ -24,11 +24,15 @@ stopping predicate is evaluated on device each round, per-round scalar
 stats land in an on-device [R]-buffer, and the host syncs only on chunk
 boundaries — instead of a full device→host round-trip per iteration.
 θ values are precomputed on the host (bit-identical to the historical
-per-round python floats) and passed as an f32[R] operand. Because each
-round runs exactly the same traced computation as the historical
-one-round-per-dispatch driver, metrics are bit-identical for any chunk
-size; ``driver_chunk=1`` recovers the historical host-synced driver
-(benchmarks/fig8_iterations.py measures the difference).
+per-round python floats) and passed as an f32[R] operand. Every chunk
+size runs the same round body inside the ``while_loop``, so metrics are
+bit-identical for any chunk size (``tests/test_engine.py`` pins 8, 1 and
+3, ``tests/dist_check.py`` the distributed ``chunk`` dispatched one round
+at a time); ``driver_chunk=1`` recovers the historical host-synced driver
+(benchmarks/fig8_iterations.py measures the difference). A straight-line
+one-round program compiled apart, such as the distributed backend's
+``step``, may order its float32 sums differently and is not bit-identical
+to the chunk.
 
 Backends in-tree: :class:`LocalBackend` below (single device; the engine
 behind ``repro.core.summarize``) and

@@ -668,7 +668,7 @@ class RoutedQueryEngine:
         self.bs = (summary if isinstance(summary, BlockSummary)
                    else build_block_summary(summary))
         self.mesh = mesh
-        self.rules = make_rules(mesh, "summarize")
+        self.rules = make_rules(mesh)
         self.salt = salt
         self.khop_max = khop_max
         self.axis_names = tuple(mesh.axis_names)
@@ -1053,7 +1053,7 @@ class PartitionedQueryEngine:
         self.bs = (summary if isinstance(summary, BlockSummary)
                    else build_block_summary(summary))
         self.mesh = mesh
-        self.rules = make_rules(mesh, "summarize")
+        self.rules = make_rules(mesh)
         self.salt = salt
         self.khop_max = khop_max
         self.dense_row_nnz = dense_row_nnz

@@ -31,7 +31,7 @@ Three entry points build the same sharded ``jax.Array`` pair:
 Both fill each shard into the staging buffer, ``device_put`` it onto its
 device, and assemble the global array with
 ``jax.make_array_from_single_device_arrays`` — the result is *born* with
-the ``summarize``-mode edge sharding (``MeshRules.edge_spec``), so
+the edge sharding (``MeshRules.edge_spec``), so
 ``jit``-ing the shard_map'd step never inserts a gather-and-reshard.
 :class:`FeedStats` records the exact staging high-water mark; the CI
 ``ingest`` job asserts ``peak_staging_bytes`` never approaches 4·|E|.
@@ -142,7 +142,7 @@ def shard_layout(num_edges: int, n_dev: int) -> tuple[int, int]:
 
 
 def _edge_sharding(mesh) -> tuple[NamedSharding, int]:
-    rules = make_rules(mesh, "summarize")
+    rules = make_rules(mesh)
     return NamedSharding(mesh, rules.edge_spec), rules.n_devices
 
 

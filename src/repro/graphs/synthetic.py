@@ -99,8 +99,9 @@ class DatasetSpec:
     note: str
 
 
-# Table 2 stand-ins (small/mid rows at full |V|; web-scale rows are dry-run
-# only — see configs/ssumm_paper.py for their ShapeDtypeStruct shapes).
+# Table 2 stand-ins (small/mid rows at full |V|). The two web-scale rows are
+# never materialized at full size on one host: their |V| and |E| are the
+# paper's Table 2 figures, which benchmarks/table2_datasets.py reports as-is.
 DATASETS: dict[str, DatasetSpec] = {
     "ego-facebook": DatasetSpec("ego-facebook", "EF", 4_039, 88_234, "caveman", "social"),
     "caida": DatasetSpec("caida", "CA", 26_475, 106_762, "rmat", "internet"),
@@ -110,8 +111,8 @@ DATASETS: dict[str, DatasetSpec] = {
     "amazon0601": DatasetSpec("amazon0601", "A6", 403_394, 2_443_408, "ba", "co-purchase"),
     "skitter": DatasetSpec("skitter", "SK", 1_696_415, 11_095_298, "rmat", "internet"),
     "livejournal": DatasetSpec("livejournal", "LJ", 3_997_962, 34_681_189, "rmat", "social"),
-    "web-uk-02": DatasetSpec("web-uk-02", "W2", 18_483_186, 261_787_258, "rmat", "hyperlinks (dry-run only)"),
-    "web-uk-05": DatasetSpec("web-uk-05", "W5", 39_454_463, 783_027_125, "rmat", "hyperlinks (dry-run only)"),
+    "web-uk-02": DatasetSpec("web-uk-02", "W2", 18_483_186, 261_787_258, "rmat", "hyperlinks (spec only)"),
+    "web-uk-05": DatasetSpec("web-uk-05", "W5", 39_454_463, 783_027_125, "rmat", "hyperlinks (spec only)"),
 }
 
 

@@ -1,6 +1,6 @@
-"""Production meshes + the multi-host bootstrap. Defined as FUNCTIONS so
-importing this module never touches jax device state (the dry-run sets
-XLA_FLAGS before any jax import).
+"""Host meshes + the multi-host bootstrap. Defined as FUNCTIONS so
+importing this module never touches jax device state (the multi-device
+checks set XLA_FLAGS before any jax import).
 
 :func:`bootstrap_distributed` is the one place the tree calls
 ``jax.distributed.initialize`` (DESIGN.md §15): it must run before the
@@ -97,20 +97,6 @@ def bootstrap_distributed(coordinator: str | None = None,
                            process_index=int(process_id))
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 v5e pod (data, model) or 2 pods (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_host_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh over host CPU devices (tests)."""
     return make_mesh(shape, axes)
-
-
-def mesh_device_count(mesh) -> int:
-    out = 1
-    for v in mesh.shape.values():
-        out *= v
-    return out

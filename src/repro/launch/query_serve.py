@@ -14,8 +14,8 @@ owner-routed :class:`RoutedQueryEngine` over every local device, or with
 ``--tier partitioned`` the memory-partitioned
 :class:`PartitionedQueryEngine`), and serves a mixed analytics workload —
 expected degree, adjacency weight, PageRank, triangle density, k-hop
-neighborhood size, cut weight, conductance — through the same static-slot
-scheduler idiom as ``launch.serve``: requests pack into a fixed
+neighborhood size, cut weight, conductance — through a static-slot
+scheduler: requests pack into a fixed
 ``--batch``-wide slot vector (static shapes ⇒ one compilation), mixed
 query types route per-slot through one fused dispatch, and finished slots
 refill from the queue each step. The JSON reports p50/p99 per-request

@@ -15,7 +15,7 @@ Design (DESIGN.md §7 fault tolerance):
     which restore ignores and the next save garbage-collects.
   * **Async**: ``save_async`` snapshots device arrays to host memory
     synchronously (cheap: device_get of sharded arrays) and hands the
-    serialization + fsync to a single background writer thread — the train
+    serialization + fsync to a single background writer thread — the engine
     loop resumes immediately (1-step decoupling). ``wait()`` joins the
     in-flight write; saves are serialized to keep the keep-N GC simple.
   * **Reshard-on-restore**: blobs are loaded as host numpy and

@@ -4,11 +4,11 @@ On a node failure the job restarts with fewer devices. ``plan_mesh`` picks
 the best (pod, data, model) factorization for the survivor count, keeping
 the model axis as close as possible to the original TP degree (params must
 still fit) and folding everything else into data parallelism. The global
-batch is preserved by scaling per-device batch (gradient accumulation picks
-up any remainder — see dist/microbatch.py).
+batch is preserved by scaling per-device batch (an accumulation count
+picks up any remainder).
 
 ``PreemptionGuard`` turns SIGTERM/SIGINT into a cooperative "save and exit"
-flag that the train loop polls once per step — the checkpoint manager's
+flag that the engine polls at each host-sync point — the checkpoint manager's
 atomic commit makes the save safe even if the grace period expires. A
 *second* signal means the grace period is over: the handler hard-exits
 immediately (``os._exit``) with the conventional ``128 + signum`` status,

@@ -14,9 +14,9 @@ Checks, on an 8-device host mesh:
      everything) degenerate branches;
   5. engine parity: SummaryEngine over the unified DistributedBackend
      (while_loop-chunked driver inside the shard_map body, then the
-     sparsify finalize) is bit-identical to the explicit per-round
-     host loop over the same step — for both driver_chunk=8 and the
-     history-equivalent driver_chunk=1.
+     sparsify finalize) is bit-identical to an explicit host loop that
+     dispatches the same ``chunk`` program one round at a time — for
+     both driver_chunk=8 and the history-equivalent driver_chunk=1.
 """
 
 import os
@@ -126,13 +126,15 @@ def check_sparsify(graph, v, e, cfg, mesh, src_p, dst_p, state, k_bits,
 
 
 def check_engine(backend, cfg, mesh, src_p, dst_p, label):
-    """SummaryEngine over the backend ≡ the explicit per-round host loop.
+    """SummaryEngine over the backend ≡ an explicit per-round host loop.
 
     The engine runs the while_loop-chunked ``backend.chunk`` program; the
-    reference drives ``backend.step`` (a separate straight-line trace) one
-    round at a time with host-python θ and the historical stopping rule —
-    every metric, the sparsify payload, and the final partition must be
-    bit-identical.
+    reference dispatches that same program one round at a time (``limit``
+    1) with host-python θ and the historical stopping rule — every metric,
+    the sparsify payload, and the final partition must be bit-identical.
+    (``backend.step``, the straight-line one-round program, is compiled
+    apart and may order its float32 sums differently; ``check_step``
+    holds it to the single-device metrics instead.)
     """
     import copy
 
@@ -145,8 +147,13 @@ def check_engine(backend, cfg, mesh, src_p, dst_p, label):
     with mesh:
         for t in range(1, cfg.T + 1):
             theta = 1.0 / (1.0 + t) if t < cfg.T else 0.0
-            state, stats = backend.step(src_p, dst_p, state,
-                                        jnp.float32(theta), jnp.uint32(t))
+            thetas = np.zeros((max(1, cfg.driver_chunk),), np.float32)
+            thetas[0] = theta
+            state, buf, rounds = backend.chunk(
+                src_p, dst_p, state, jnp.asarray(thetas), jnp.uint32(t),
+                jnp.float32(k_bits), jnp.int32(1))
+            assert int(rounds) == 1, (label, t, int(rounds))
+            stats = {k: np.asarray(v)[0] for k, v in buf.items()}
             if float(stats["size_bits"]) <= k_bits:
                 break
         ref_sp, _ = backend.sparsify(src_p, dst_p, state,
@@ -236,7 +243,7 @@ def main():
 
     # group ownership concentrates records (few groups at tiny |V|), so the
     # routing capacity factor is raised here; at web scale the expected
-    # per-destination load is E/n_dev² ≪ cap (see dryrun_ssumm)
+    # per-destination load is E/n_dev² ≪ cap
     step_c = make_distributed_step_compact(mesh, cfg, v, e,
                                            capacity_factor=64.0)
     merged_c, final_c = check_step(step_c, graph, v, e, cfg, mesh, src_p,

@@ -5,7 +5,7 @@ must be launcher-JSON bit-identical to the single-process 8-device golden.
         --edge-list data/rmat_1m.txt.gz --T 15 --driver-chunk 1 \
         --rss-budget-mb 3072 --out multihost_report.json
 
-Four legs, each a real ``repro.launch.summarize`` invocation (the harness
+Three legs, each a real ``repro.launch.summarize`` invocation (the harness
 never imports jax — every subprocess owns its device topology):
 
   golden    — 1 process × (P·D) devices, ``--distributed``; also warms
@@ -26,9 +26,6 @@ never imports jax — every subprocess owns its device topology):
               must again match the golden bit-for-bit (PR 7's machinery,
               now with process-0 writes + cross-process preemption
               agreement).
-  wire      — ``tests/wire_check.py`` on the same 2-process mesh: the
-              compressed-payload byte counters and error-feedback
-              locality, across a real process boundary.
 
 ``--bench-out`` writes per-leg wall clocks in the
 ``scripts/check_bench.py --bench multihost`` artifact format.
@@ -181,8 +178,6 @@ def main():
     ap.add_argument("--rss-budget-mb", type=float, default=None,
                     help="per-process peak-RSS gate for every leg (the "
                          "no-full-|E|-staging proof)")
-    ap.add_argument("--skip-wire", action="store_true",
-                    help="skip the wire_check leg")
     ap.add_argument("--workdir", default=None)
     ap.add_argument("--timeout", type=float, default=900.0)
     ap.add_argument("--out", default=None,
@@ -314,39 +309,6 @@ def main():
             leg["outcome"] = "resumed"
     walls["resume"] = time.time() - t0
     report["legs"]["resume"] = leg
-
-    # ---- wire: compressed all-reduce accounting across the boundary ------
-    if not args.skip_wire:
-        t0 = time.time()
-        port = free_port()
-        procs, files = [], []
-        for i in range(args.num_processes):
-            env = env_for(args.devices_per_process)
-            env.update(SSUMM_COORDINATOR=f"localhost:{port}",
-                       SSUMM_NUM_PROCESSES=str(args.num_processes),
-                       SSUMM_PROCESS_ID=str(i))
-            out = open(os.path.join(workdir, f"wire_p{i}.out"), "w+")
-            procs.append(subprocess.Popen(
-                [sys.executable, os.path.join(ROOT, "tests",
-                                              "wire_check.py")],
-                env=env, stdout=out, stderr=subprocess.DEVNULL))
-            files.append(out)
-        leg = {"rcs": []}
-        for i, (p, f) in enumerate(zip(procs, files)):
-            try:
-                rc = p.wait(timeout=args.timeout)
-            except subprocess.TimeoutExpired:
-                p.kill()
-                rc = p.wait()
-            leg["rcs"].append(rc)
-            f.flush()
-            f.seek(0)
-            body = f.read()
-            f.close()
-            if rc != 0:
-                fail(f"wire p{i} rc={rc}: {body[-1500:]}")
-        walls["wire"] = time.time() - t0
-        report["legs"]["wire"] = leg
 
     report["walls"] = walls
     text = json.dumps(report, indent=1)

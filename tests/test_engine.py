@@ -1,15 +1,14 @@
-"""SummaryEngine regression against pre-refactor golden metrics.
+"""SummaryEngine: chunk-size invariance, an independent check of the final
+summary, checkpoint/resume and preemption.
 
-``tests/golden/engine_local.json`` was recorded from the straight-line
-one-round-per-dispatch driver the engine replaced: every per-round history
-metric and the final ``SummaryResult`` must stay bit-identical through the
-while_loop-chunked driver, for any chunk size (``driver_chunk=1`` is the
+Every per-round history metric and the final ``SummaryResult`` are
+bit-identical for any ``driver_chunk`` (``driver_chunk=1`` is the
 history-equivalent sync-every-round mode; the distributed analogue lives in
-``tests/dist_check.py``).
+``tests/dist_check.py``), and the final summary agrees with a float64
+dense reconstruction of the same partition.
 """
 
 import dataclasses
-import json
 import pathlib
 
 import numpy as np
@@ -23,6 +22,12 @@ from repro.core.engine import (
     SummaryEngine,
     theta_schedule_host,
 )
+from repro.core.evaluate import (
+    dense_adjacency,
+    re_p_dense,
+    reconstruct_dense,
+    summary_size_bits_dense,
+)
 from repro.graphs import generate
 from repro.runtime import (
     CheckpointManager,
@@ -31,45 +36,91 @@ from repro.runtime import (
     StragglerMonitor,
 )
 
-GOLDEN = pathlib.Path(__file__).parent / "golden" / "engine_local.json"
+#: the chunk-invariance fixture: a 323-node ego-facebook stand-in
+FIXTURE = dict(name="ego-facebook", seed=0, scale=0.08)
+CONFIG = dict(T=10, k_frac=0.3, seed=1)
 
 HISTORY_KEYS = ("size_bits", "re1", "re2", "nmerges", "num_supernodes",
                 "num_superedges", "mdl_cost", "t", "theta")
 
+#: SummaryResult fields that are wall-clock bookkeeping, not results
+TIMING_FIELDS = ("chunk_wall_s", "checkpoint_snapshot_wall_s")
+
+#: relative gap allowed between the engine's float32 RE₁, RE₂ and Size(Ḡ)
+#: and the float64 dense reference. Each is a float32 sum of a few hundred
+#: terms at |V| = 323 (489 superedges over 186 supernodes), each rounded at
+#: the float32 unit roundoff 2⁻²⁴ ≈ 6e-8: the gaps read 2.1e-8 (RE₁),
+#: 7e-9 (RE₂) and 2.3e-8 (size), and 1e-6 (about 17 roundoffs) leaves
+#: ~40× room. One wrong superedge moves Size(Ḡ) by ~22 bits of 13.1k
+#: (≈2e-3), so the bound still sees any wrong term.
+DENSE_RTOL = 1e-6
+
 
 def _load():
-    g = json.loads(GOLDEN.read_text())
-    fx, cfg = g["fixture"], g["config"]
-    src, dst, v = generate(fx["dataset"], seed=fx["gen_seed"],
-                           scale=fx["scale"])
-    assert v == fx["V"]
-    return src, dst, v, cfg, g
+    return generate(**FIXTURE)
+
+
+@pytest.fixture(scope="module")
+def chunk8_run():
+    src, dst, v = _load()
+    return summarize(src, dst, v, SummaryConfig(**CONFIG, driver_chunk=8))
+
+
+def _assert_results_identical(got, want):
+    for f in dataclasses.fields(want):
+        if f.name in TIMING_FIELDS:
+            continue
+        a, b = getattr(got, f.name), getattr(want, f.name)
+        if isinstance(b, np.ndarray):
+            assert a.dtype == b.dtype and np.array_equal(a, b), f.name
+        else:
+            assert a == b, (f.name, a, b)
+
+
+def _assert_matches_dense_reference(res, src, dst, v):
+    a = dense_adjacency(src, dst, v)
+    a_hat = reconstruct_dense(res)
+    for p, got in ((1, res.re1), (2, res.re2)):
+        want = re_p_dense(a, a_hat, p)
+        assert abs(got - want) <= DENSE_RTOL * want, (p, got, want)
+    size = summary_size_bits_dense(res)
+    assert abs(res.size_bits - size) <= DENSE_RTOL * size, (res.size_bits,
+                                                            size)
+    # supernode sizes and superedge weights: exact counts from the partition
+    n2s = res.node2super
+    np.testing.assert_array_equal(res.super_size,
+                                  np.bincount(n2s, minlength=v))
+    assert res.num_supernodes == len(np.unique(n2s))
+    lo = np.minimum(src, dst)
+    hi = np.maximum(src, dst)
+    keep = lo != hi
+    ekey = np.unique(lo[keep].astype(np.int64) * v + hi[keep])
+    s_lo = np.minimum(n2s[ekey // v], n2s[ekey % v]).astype(np.int64)
+    s_hi = np.maximum(n2s[ekey // v], n2s[ekey % v]).astype(np.int64)
+    pair, count = np.unique(s_lo * v + s_hi, return_counts=True)
+    got = res.edge_lo.astype(np.int64) * v + res.edge_hi
+    assert res.num_superedges == len(got) == len(np.unique(got))
+    pos = np.searchsorted(pair, got)
+    assert np.all(pair[np.minimum(pos, len(pair) - 1)] == got)
+    np.testing.assert_array_equal(res.edge_w, count[pos])
 
 
 @pytest.mark.parametrize("driver_chunk", [8, 1, 3])
-def test_local_engine_matches_golden(driver_chunk):
-    src, dst, v, cfg_d, g = _load()
-    cfg = SummaryConfig(T=cfg_d["T"], k_frac=cfg_d["k_frac"],
-                        seed=cfg_d["seed"], driver_chunk=driver_chunk)
-    res = summarize(src, dst, v, cfg)
+def test_local_engine_matches_golden(driver_chunk, chunk8_run):
+    """Any chunk size (8 again, 1, 3) reproduces the ``driver_chunk=8`` run
+    bit for bit, and its summary matches the float64 dense reference."""
+    src, dst, v = _load()
+    res = summarize(src, dst, v,
+                    SummaryConfig(**CONFIG, driver_chunk=driver_chunk))
 
-    assert len(res.history) == len(g["history"])
-    for got, want in zip(res.history, g["history"]):
-        for k in HISTORY_KEYS:
+    assert len(res.history) == len(chunk8_run.history) == CONFIG["T"]
+    for got, want in zip(res.history, chunk8_run.history):
+        assert got.keys() == want.keys()
+        for k in want:
             assert got[k] == want[k], (driver_chunk, got["t"], k,
                                        got[k], want[k])
-
-    final = g["final"]
-    assert res.size_bits == final["size_bits"]
-    assert res.input_size_bits == final["input_size_bits"]
-    assert res.re1 == final["re1"]
-    assert res.re2 == final["re2"]
-    assert res.mdl_cost == final["mdl_cost"]
-    assert res.num_supernodes == final["num_supernodes"]
-    assert res.num_superedges == final["num_superedges"]
-    assert res.iterations_run == final["iterations_run"]
-    assert int(np.sum(res.node2super)) == final["node2super_sum"]
-    assert int(np.sum(res.edge_w)) == final["edge_w_sum"]
+    _assert_results_identical(res, chunk8_run)
+    _assert_matches_dense_reference(res, src, dst, v)
 
 
 def test_theta_schedule_host_matches_paper():
@@ -81,7 +132,7 @@ def test_theta_schedule_host_matches_paper():
 
 def test_engine_run_payload_consistent():
     """EngineRun bookkeeping: k_bits, last_stats, and history agree."""
-    src, dst, v, cfg_d, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=4, k_frac=0.3, seed=1)
     backend = LocalBackend(src, dst, v, cfg)
     run = SummaryEngine(backend).run()
@@ -127,7 +178,7 @@ def test_local_resume_bit_identical(tmp_path, resume_chunk):
     even under a different ``driver_chunk`` — must reproduce every final
     metric and the partition bit-for-bit.
     """
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=10, k_frac=0.2, seed=0, driver_chunk=3)
     golden = summarize(src, dst, v, cfg)
 
@@ -158,7 +209,7 @@ def test_local_resume_bit_identical(tmp_path, resume_chunk):
 
 def test_resume_from_final_phase_skips_merging(tmp_path):
     """A crash inside the sparsify tail resumes straight to finalize."""
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=4, k_frac=0.3, seed=0)
     golden = summarize(src, dst, v, cfg)
 
@@ -177,7 +228,7 @@ def test_resume_from_final_phase_skips_merging(tmp_path):
 def test_resume_fingerprint_gate(tmp_path):
     """A checkpoint is only resumable under the identical config+graph —
     except ``driver_chunk``, whose bit-identity across values is proven."""
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=4, k_frac=0.3, seed=0)
     ck = _ckp(tmp_path, every=1)
     summarize(src, dst, v, cfg, checkpointer=ck)
@@ -201,7 +252,7 @@ def test_resume_fingerprint_gate(tmp_path):
 def test_resume_with_empty_dir_starts_fresh(tmp_path):
     """``--resume`` against a dir with nothing committed is a cold start,
     not an error — the idempotent supervisor retry loop depends on it."""
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=3, k_frac=0.3, seed=0)
     golden = summarize(src, dst, v, cfg)
     res = summarize(src, dst, v, cfg, checkpointer=_ckp(tmp_path, every=1),
@@ -211,7 +262,7 @@ def test_resume_with_empty_dir_starts_fresh(tmp_path):
 
 
 def test_resume_requires_checkpointer():
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     backend = LocalBackend(src, dst, v, SummaryConfig(T=2))
     with pytest.raises(ValueError, match="checkpointer"):
         SummaryEngine(backend).run(resume=True)
@@ -220,7 +271,7 @@ def test_resume_requires_checkpointer():
 def test_preemption_saves_and_raises(tmp_path):
     """A pending signal is honored at the next host-sync point: the state
     is saved synchronously and ``Preempted`` carries the committed step."""
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=10, k_frac=0.2, seed=0, driver_chunk=2)
     guard = PreemptionGuard(signals=())
     guard._requested = True  # signal already pending before the run
@@ -239,7 +290,7 @@ def test_preemption_saves_and_raises(tmp_path):
 
 
 def test_straggler_monitor_brackets_dispatches():
-    src, dst, v, _, _ = _load()
+    src, dst, v = _load()
     cfg = SummaryConfig(T=6, k_frac=0.2, seed=0, driver_chunk=2)
     mon = StragglerMonitor(warmup_steps=1000)  # never flags
     res = summarize(src, dst, v, cfg, monitor=mon)

@@ -1,5 +1,5 @@
 """Runtime substrate: checkpoint atomicity/restore, elastic mesh planning,
-straggler detection, data determinism, gradient compression."""
+straggler detection."""
 
 import os
 
@@ -9,9 +9,6 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.data import Loader, SyntheticTokens, TokenDatasetConfig
-from repro.dist import CompressConfig, decode_int8, encode_int8, encode_topk
-from repro.dist.compress import init_error_buffers, payload_bytes
 from repro.runtime import CheckpointManager, StragglerMonitor, plan_mesh
 
 
@@ -146,70 +143,3 @@ def test_straggler_callback():
         mon.observe(i, 0.05)
     mon.observe(10, 0.5)
     assert len(seen) == 1 and seen[0].step == 10
-
-
-# ---------------------------------------------------------------------------
-# data determinism
-# ---------------------------------------------------------------------------
-
-
-def test_tokens_deterministic_and_sharded():
-    cfg = TokenDatasetConfig(vocab=128, seq_len=32, global_batch=8, seed=3)
-    ds = SyntheticTokens(cfg)
-    a, b = ds.batch(5), ds.batch(5)
-    np.testing.assert_array_equal(a, b)
-    assert not np.array_equal(ds.batch(5), ds.batch(6))
-    assert a.min() >= 0 and a.max() < 128
-    # rank shards tile the global batch exactly
-    parts = [ds.batch_for_rank(5, r, 4) for r in range(4)]
-    np.testing.assert_array_equal(np.concatenate(parts), a)
-
-
-def test_loader_resume_stream():
-    cfg = TokenDatasetConfig(vocab=64, seq_len=16, global_batch=2, seed=0)
-    ds = SyntheticTokens(cfg)
-    loader = Loader(ds.batch, start_index=3, prefetch=2)
-    idx, batch = next(loader)
-    assert idx == 3
-    np.testing.assert_array_equal(np.asarray(batch), ds.batch(3))
-    loader.close()
-
-
-# ---------------------------------------------------------------------------
-# gradient compression
-# ---------------------------------------------------------------------------
-
-
-def test_int8_roundtrip_error_bound():
-    rng = np.random.default_rng(0)
-    g = {"a": jnp.asarray(rng.standard_normal((64, 64)), jnp.float32)}
-    q, s = encode_int8(g)
-    back = decode_int8(q, s)
-    err = float(jnp.max(jnp.abs(back["a"] - g["a"])))
-    assert err <= float(s["a"]) * 0.5 + 1e-6  # half-ULP of the scale
-
-
-def test_topk_error_feedback_conserves_signal():
-    """Over many steps, sent + residual ≡ the accumulated gradient signal."""
-    rng = np.random.default_rng(1)
-    g = {"a": jnp.asarray(rng.standard_normal(256), jnp.float32)}
-    err = init_error_buffers(g)
-    sent_total = jnp.zeros(256)
-    for _ in range(5):
-        sent, err = encode_topk(g, err, ratio=0.1)
-        sent_total = sent_total + sent["a"]
-        nz = int(jnp.sum(sent["a"] != 0.0))
-        assert nz <= 26  # ~top 10%
-    recon = sent_total + err["a"]
-    np.testing.assert_allclose(np.asarray(recon), 5 * np.asarray(g["a"]),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_payload_accounting():
-    g = {"a": jnp.zeros((1000,), jnp.float32)}
-    full = payload_bytes(g, CompressConfig("none"))
-    int8 = payload_bytes(g, CompressConfig("int8"))
-    topk = payload_bytes(g, CompressConfig("topk", topk_ratio=0.05))
-    assert full == 4000.0
-    assert int8 < full / 3.5
-    assert topk < full / 9.0

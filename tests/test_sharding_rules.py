@@ -1,15 +1,14 @@
-"""MeshRules / make_rules: mode tables, spec assembly, override validation.
+"""MeshRules / make_rules: the edge-sharding table and the ownership hash.
 
 Runs on the single CPU device (a 1×1 mesh exercises the full code path —
-axis *names* are what the validation is about, not axis sizes)."""
+axis *names* are what the table is about, not axis sizes)."""
 
 import jax
 import numpy as np
-import pytest
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import MODES, MeshRules, make_rules, owner_hash_np
+from repro.dist.sharding import MeshRules, make_rules, owner_hash_np
 
 
 def _mesh(axes=("data", "model")):
@@ -17,33 +16,19 @@ def _mesh(axes=("data", "model")):
     return Mesh(devs, axes)
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_mode_tables_build_on_any_mesh(mode):
-    rules = make_rules(_mesh(), mode)
-    assert rules.n_devices == 1
-    assert rules.axis_names == ("data", "model")
-    # every logical name resolves without KeyError
-    for name in rules.table:
-        rules.mesh_axes(name)
-    with pytest.raises(KeyError):
-        rules.mesh_axes("not_a_logical_axis")
+def test_rules_build_on_any_mesh():
+    for axes in (("data",), ("data", "model"), ("pod", "data", "model")):
+        rules = make_rules(_mesh(axes))
+        assert rules.n_devices == 1
+        assert rules.axis_names == axes
+        assert rules.edge_spec == P(axes[0] if len(axes) == 1 else axes)
+        assert rules.replicated == P()
 
 
 def test_summarize_mode_shards_edges_over_all_axes():
-    rules = make_rules(_mesh(), "summarize")
+    rules = make_rules(_mesh())
     assert rules.edge_spec == P(("data", "model"))
     assert rules.replicated == P()
-
-
-def test_eval_mode_table_is_pure_data_parallel():
-    """Offline eval: the batch spreads over every mesh axis, weights and
-    activations replicate — no tensor-parallel assignment survives."""
-    rules = make_rules(_mesh(), "eval")
-    assert rules.mesh_axes("batch") == ("data", "model")
-    for name, assign in rules.table.items():
-        if name != "batch":
-            assert assign is None, name
-    assert rules.spec(("batch",)) == P(("data", "model"))
 
 
 def test_owner_hash_np_matches_device_hash():
@@ -57,45 +42,10 @@ def test_owner_hash_np_matches_device_hash():
     ids = np.arange(1024, dtype=np.int32)
     for n_dev in (1, 4, 8):
         mesh = types.SimpleNamespace(size=n_dev, axis_names=("data",))
-        rules = MeshRules(mesh=mesh, mode="summarize", table={})
+        rules = MeshRules(mesh=mesh)
         for salt in (0, 1, 17, 2**31 - 1):
             want = np.asarray(rules.owner(jnp.asarray(ids),
                                           jnp.uint32(salt)))
             got = owner_hash_np(ids, salt, n_dev)
             assert np.array_equal(got, want), (n_dev, salt)
             assert got.min() >= 0 and got.max() < n_dev
-
-
-def test_override_unknown_logical_name_raises():
-    with pytest.raises(KeyError, match="unknown logical axis 'sequ'"):
-        make_rules(_mesh(), "serve", overrides={"sequ": "model"})
-
-
-def test_override_unknown_mesh_axis_raises():
-    """The ROADMAP gap: 'seq=modell' used to silently replicate."""
-    with pytest.raises(ValueError, match="not an axis of this mesh"):
-        make_rules(_mesh(), "serve", overrides={"seq": "modell"})
-    with pytest.raises(ValueError, match="mesh axes: \\('data', 'model'\\)"):
-        make_rules(_mesh(), "train", overrides={"batch": ("data", "pod")})
-
-
-def test_override_duplicate_mesh_axis_raises():
-    with pytest.raises(ValueError, match="more than once"):
-        make_rules(_mesh(), "train", overrides={"batch": ("data", "data")})
-
-
-def test_override_non_string_entry_raises():
-    with pytest.raises(ValueError):
-        make_rules(_mesh(), "train", overrides={"batch": (1,)})
-
-
-def test_valid_overrides_accepted():
-    rules = make_rules(_mesh(), "serve",
-                       overrides={"seq": None, "batch": ("data", "model")})
-    assert rules.table["seq"] is None
-    assert rules.table["batch"] == ("data", "model")
-    # owner hash stays well-defined after overrides
-    import jax.numpy as jnp
-
-    own = rules.owner(jnp.arange(16, dtype=jnp.int32), jnp.uint32(3))
-    assert int(own.max()) < rules.n_devices

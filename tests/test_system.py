@@ -1,65 +1,7 @@
-"""End-to-end system tests: the three drivers run as a user would run them
-(in-process via their main(argv)), exercising mesh planning, sharded init,
-checkpointing, and the serving scheduler on CPU."""
-
-import json
-import os
-import subprocess
-import sys
+"""End-to-end system test: the summarize driver runs as a user would run it
+(in-process via its main(argv)) on the CPU."""
 
 import numpy as np
-import pytest
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_train_driver_end_to_end(tmp_path):
-    from repro.launch.train import main
-
-    res = main([
-        "--arch", "h2o_danube_1_8b", "--smoke", "--steps", "8",
-        "--batch", "4", "--seq", "32", "--ckpt-dir", str(tmp_path),
-        "--ckpt-every", "4", "--log-every", "100",
-    ])
-    assert res["steps"] == 8
-    assert np.isfinite(res["loss_last"])
-    # checkpoints committed: async at 4, 8 + final at 8
-    from repro.runtime import CheckpointManager
-
-    assert CheckpointManager(str(tmp_path)).latest_step() == 8
-
-
-def test_train_driver_resume(tmp_path):
-    from repro.launch.train import main
-
-    main(["--arch", "xlstm_350m", "--smoke", "--steps", "6", "--batch", "2",
-          "--seq", "16", "--ckpt-dir", str(tmp_path), "--ckpt-every", "3",
-          "--log-every", "100"])
-    res = main(["--arch", "xlstm_350m", "--smoke", "--steps", "9",
-                "--batch", "2", "--seq", "16", "--ckpt-dir", str(tmp_path),
-                "--resume", "--ckpt-every", "3", "--log-every", "100"])
-    assert res["steps"] == 3  # resumed at 6, ran 6..8
-
-
-def test_train_loss_decreases():
-    """~40 steps on the structured synthetic corpus must cut the loss."""
-    from repro.launch.train import main
-
-    res = main(["--arch", "qwen2_5_14b", "--smoke", "--steps", "40",
-                "--batch", "8", "--seq", "64", "--lr", "3e-3",
-                "--log-every", "100"])
-    assert res["loss_last"] < res["loss_first"] - 0.3, res
-
-
-def test_serve_driver_end_to_end():
-    from repro.launch.serve import main
-
-    res = main(["--arch", "gemma_7b", "--smoke", "--requests", "3",
-                "--slots", "2", "--prompt-len", "4", "--gen-len", "4",
-                "--max-len", "32"])
-    assert res["requests"] == 3
-    assert res["tokens"] == 3 * 4
-    assert res["tok_per_s"] > 0
 
 
 def test_summarize_driver_end_to_end():
@@ -69,32 +11,3 @@ def test_summarize_driver_end_to_end():
                 "--k-frac", "0.3", "--T", "5"])
     assert res["relative_size"] <= 0.3 + 1e-6
     assert np.isfinite(res["re1"])
-
-
-def test_grad_accumulation_matches_full_batch():
-    """accum=2 must produce (numerically) the same update as accum=1."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import get_smoke_config
-    from repro.dist import microbatch_grads
-    from repro.models.api import build_model
-
-    cfg = get_smoke_config("h2o_danube_1_8b")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
-                                          cfg.vocab)}
-
-    def loss_fn(p, b):
-        return model.loss(p, b, None, remat=False)
-
-    l1, _, g1 = microbatch_grads(loss_fn, params, batch, accum=1)
-    l2, _, g2 = microbatch_grads(loss_fn, params, batch, accum=2)
-    np.testing.assert_allclose(float(l1), float(l2), rtol=2e-5)
-    flat1 = jnp.concatenate([x.ravel().astype(jnp.float32)
-                             for x in jax.tree.leaves(g1)])
-    flat2 = jnp.concatenate([x.ravel().astype(jnp.float32)
-                             for x in jax.tree.leaves(g2)])
-    np.testing.assert_allclose(np.asarray(flat1), np.asarray(flat2),
-                               rtol=5e-3, atol=5e-5)
